@@ -59,6 +59,9 @@ def main() -> None:
     import jax.numpy as jnp
     import numpy as np
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from repro.channels.geometry import GeometryConfig, relative_gains_block
     from repro.core.channel import ChannelConfig, draw_channel_block
     from repro.fed import runtime
@@ -145,6 +148,7 @@ def main() -> None:
         "grad_norm_mean_final": float(hist["grad_norm_mean"][-1]),
         "params_sha256": params_sha,
         "local_devices": jax.local_device_count(),
+        "platform": jax.default_backend(),
         # self-describing identity block: config hash + structural signature
         # + the digest above (compare.py --manifest cross-checks signatures)
         "manifest": obs.run_manifest(cfg=cfg, params_digest=params_sha),

@@ -28,6 +28,9 @@ def main() -> None:
 
     from benchmarks import common, figures
     from benchmarks.roofline_table import roofline_rows
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     if args.backend:
         common.DEFAULT_BACKEND = args.backend
@@ -39,6 +42,12 @@ def main() -> None:
     # bands; quick mode trims rounds AND replicates
     s = r(3, 2)
     benches = [
+        # the streaming K-scale engine: a 100,000-device round (k_block
+        # lax.scan superposition) vs the dense path's linear peak-RSS growth
+        # — subprocess cases, flat-memory + absolute-pin guards asserted.
+        # FIRST: its children need the accelerator, which this process
+        # must not hold yet (one process per chip)
+        ("kscale", lambda: figures.kscale_flat_memory(quick=args.quick)),
         ("fig1a", lambda: figures.fig1a_opt_benefit(r(300, 60), s)),
         ("fig1b", lambda: figures.fig1b_benchmarks(r(300, 60), s)),
         ("fig2a", lambda: figures.fig2a_opt_benefit_ridge(r(400, 80), s)),
@@ -57,10 +66,6 @@ def main() -> None:
         # CSI re-solve must stay within 2x of plain fading, asserted) and
         # the CSI-robustness figure (scheme x csi_error x seed bands)
         ("channel", lambda: figures.channel_rounds_per_sec(r(256, 96))),
-        # the streaming K-scale engine: a 100,000-device round (k_block
-        # lax.scan superposition) vs the dense path's linear peak-RSS growth
-        # — subprocess cases, flat-memory + absolute-pin guards asserted
-        ("kscale", lambda: figures.kscale_flat_memory(quick=args.quick)),
         ("csi_robustness", lambda: figures.csi_robustness(r(400, 60))),
         # the client-algorithm registry: FedProx / FedDyn / SCAFFOLD vs
         # local SGD on dirichlet splits with H=4 local steps — the
@@ -76,9 +81,24 @@ def main() -> None:
         keep = set(args.only.split(","))
         benches = [b for b in benches if b[0] in keep]
 
+    print("name,us_per_call,derived")
+    failed = []
+    for name, fn in benches:
+        t0 = time.time()
+        try:
+            rows = fn()
+        except Exception as e:  # keep the harness alive; report the failure
+            print(f"{name},0,ERROR={e!r}", flush=True)
+            failed.append(name)
+            continue
+        for row in rows:
+            print(",".join(str(c) for c in row), flush=True)
+        print(f"# {name} took {time.time()-t0:.1f}s", file=sys.stderr)
+
     # harness-level run manifest: which benchmarks ran, with which knobs, on
     # which jax/platform — makes a whole results/ directory self-describing
-    # (each bench_*.json additionally embeds its own per-config manifests)
+    # (each bench_*.json additionally embeds its own per-config manifests).
+    # Written last: it asks JAX for the backend, which takes the chip
     import json
     import os
 
@@ -94,20 +114,6 @@ def main() -> None:
             "backend": common.DEFAULT_BACKEND,
             "driver": common.DEFAULT_DRIVER,
         }), f, indent=2, default=str)
-
-    print("name,us_per_call,derived")
-    failed = []
-    for name, fn in benches:
-        t0 = time.time()
-        try:
-            rows = fn()
-        except Exception as e:  # keep the harness alive; report the failure
-            print(f"{name},0,ERROR={e!r}", flush=True)
-            failed.append(name)
-            continue
-        for row in rows:
-            print(",".join(str(c) for c in row), flush=True)
-        print(f"# {name} took {time.time()-t0:.1f}s", file=sys.stderr)
     if failed:
         # the CI smoke relies on in-benchmark assertions (e.g. the channel
         # benchmark's 2x CSI-refresh budget) actually failing the job — a

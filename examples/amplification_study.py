@@ -13,6 +13,7 @@ import math
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import (case2_bias_floor, optimal_S, solve_problem3)
 from repro.core.channel import ChannelConfig, draw_channel
 
@@ -21,6 +22,7 @@ B_MAX = math.sqrt(5.0)
 
 
 def main() -> None:
+    enable_compile_cache()
     print("=== Problem 3 solution structure vs noise level ===")
     print(f"{'n*sigma^2':>12s} {'Z':>12s} {'#b at cap':>10s} {'cv(h*b)':>10s}")
     cfg = ChannelConfig(num_devices=K, channel_mean=1e-3)

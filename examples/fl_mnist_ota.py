@@ -12,10 +12,12 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
+from repro.compile_cache import enable_compile_cache
 from repro.fl import Experiment
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--rounds", type=int, default=300)
     ap.add_argument("--scheme", default="all",

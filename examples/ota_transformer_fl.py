@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs.registry import get_config, reduce_config
 from repro.core import amplification as amp
 from repro.core.channel import ChannelConfig, draw_channel
@@ -30,6 +31,7 @@ from repro.optim.optimizers import sgd, inverse_power_schedule
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="granite-moe-1b-a400m")
     ap.add_argument("--steps", type=int, default=30)

@@ -32,6 +32,7 @@ import dataclasses
 
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import case2_bound, solve_problem3, solve_problem3_jax
 from repro.core.channel import ChannelConfig
 from repro.fl import (DataSpec, EvalSpec, Experiment, ExperimentSpec,
@@ -41,6 +42,7 @@ K, DIM = 20, 30
 
 
 def main() -> None:
+    enable_compile_cache()
     chan = ChannelConfig(num_devices=K, channel_mean=1e-3)
     spec = ExperimentSpec(
         fl=FLConfig(num_devices=K, scheme="normalized", case="II", eta=0.01,

@@ -12,6 +12,7 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs.registry import get_config, reduce_config
 from repro.launch.mesh import make_host_mesh
 from repro.launch import serve as serve_lib
@@ -19,6 +20,7 @@ from repro.models import transformer as T
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="xlstm-1.3b")
     ap.add_argument("--batch", type=int, default=8)

@@ -1,8 +1,3 @@
 """Reproduction of "Over-the-Air Computation Aided Federated Learning with
 the Aggregation of Normalized Gradient" as a production-scale jax system.
-
-Importing this package installs the jax forward-compatibility shims
-(``repro.compat``) so the modern mesh API (``jax.shard_map`` / ``jax.set_mesh``)
-works on older pinned jax versions too.
 """
-from repro import compat as _compat  # noqa: F401  (side-effect: jax API shims)

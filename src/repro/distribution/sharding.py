@@ -42,6 +42,13 @@ FL_DEVICE_AXIS = "fldev"
 # the first run of a config, or call runtime.clear_compile_caches() after.
 _EMULATE_ENV = "REPRO_FL_MESH"
 
+# Both 1-D meshes are Auto-typed: the engine places its inputs itself
+# (device_put / shard_map specs / sharding constraints).  jax.make_mesh
+# defaults to Explicit axes, which put the mesh into the array types: the
+# shard_map bodies then fail on closed-over params, and the batched sweep's
+# scan cannot find the experiment axis.
+_AUTO = (jax.sharding.AxisType.Auto,)
+
 
 def experiment_mesh(num_experiments: int, *, axis_name: str = EXPERIMENT_AXIS,
                     devices=None, require: bool = False):
@@ -77,7 +84,8 @@ def experiment_mesh(num_experiments: int, *, axis_name: str = EXPERIMENT_AXIS,
                 "restrict jax to a dividing subset, or drop require=True to "
                 "run replicated on one device")
         return None
-    return jax.make_mesh((len(devices),), (axis_name,), devices=devices)
+    return jax.make_mesh((len(devices),), (axis_name,), axis_types=_AUTO,
+                         devices=devices)
 
 
 def device_mesh(num_shards: int, *, axis_name: str = FL_DEVICE_AXIS,
@@ -95,7 +103,7 @@ def device_mesh(num_shards: int, *, axis_name: str = FL_DEVICE_AXIS,
     devices = list(jax.local_devices() if devices is None else devices)
     if num_shards == 1 or len(devices) < num_shards:
         return None
-    return jax.make_mesh((num_shards,), (axis_name,),
+    return jax.make_mesh((num_shards,), (axis_name,), axis_types=_AUTO,
                          devices=devices[:num_shards])
 
 

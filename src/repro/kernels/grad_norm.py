@@ -19,6 +19,12 @@ scaled — HBM-bandwidth-bound reductions.  Two kernels:
 The (tiny) final block-sum + sqrt happens in the jitted wrappers
 (``ops.batched_moments`` / ``ops.batched_grad_norms``).
 
+Output layout: Mosaic stores vectors, not scalars, to VMEM, and every block's
+last two dims must be (8, 128)-tiled or span the whole array.  So each
+partial is written as a lane row — the block's scalar broadcast over
+``LANE`` lanes, in a trailing ``(1, LANE)`` that spans the array's last two
+dims — and the wrappers read lane 0.
+
 Target: TPU (MXU/VPU 8x128 tiling); validated on CPU via interpret=True
 against ``ref.grad_norm_ref`` / ``ref.batched_moments_ref``.
 """
@@ -30,10 +36,13 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+LANE = 128  # one vreg row: the width of every partial-sum output row
+
 
 def _sumsq_kernel(x_ref, out_ref):
-    x = x_ref[...].astype(jnp.float32)
-    out_ref[0, 0] = jnp.sum(x * x)
+    x = x_ref[...].astype(jnp.float32)              # [br, cols]
+    sq = jnp.sum(x * x, axis=(0, 1), keepdims=True)
+    out_ref[...] = jnp.broadcast_to(sq[None], out_ref.shape)
 
 
 def blocked_sumsq(x: jax.Array, *, block_rows: int = 256,
@@ -51,17 +60,19 @@ def blocked_sumsq(x: jax.Array, *, block_rows: int = 256,
         _sumsq_kernel,
         grid=grid,
         in_specs=[pl.BlockSpec((br, cols), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, 1), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((grid[0], 1), jnp.float32),
+        out_specs=pl.BlockSpec((1, 1, LANE), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((grid[0], 1, LANE), jnp.float32),
         interpret=interpret,
     )(x)
-    return out[:, 0]
+    return out[:, 0, 0]
 
 
 def _moments_kernel(x_ref, sq_ref, s_ref):
     x = x_ref[0].astype(jnp.float32)          # [br, cols] tile of device i
-    sq_ref[0, 0] = jnp.sum(x * x)
-    s_ref[0, 0] = jnp.sum(x)
+    sq = jnp.sum(x * x, axis=(0, 1), keepdims=True)
+    s = jnp.sum(x, axis=(0, 1), keepdims=True)
+    sq_ref[...] = jnp.broadcast_to(sq[None, None], sq_ref.shape)
+    s_ref[...] = jnp.broadcast_to(s[None, None], s_ref.shape)
 
 
 def batched_blocked_moments(x: jax.Array, *, block_rows: int = 256,
@@ -77,17 +88,17 @@ def batched_blocked_moments(x: jax.Array, *, block_rows: int = 256,
     if rows % br != 0:
         raise ValueError(f"block_rows {br} must divide rows {rows}")
     grid = (k, rows // br)
-    out_shape = jax.ShapeDtypeStruct((k, grid[1]), jnp.float32)
+    out_shape = jax.ShapeDtypeStruct((k, grid[1], 1, LANE), jnp.float32)
+    out_spec = pl.BlockSpec((1, 1, 1, LANE), lambda i, j: (i, j, 0, 0))
     sumsq, sums = pl.pallas_call(
         _moments_kernel,
         grid=grid,
         in_specs=[pl.BlockSpec((1, br, cols), lambda i, j: (i, j, 0))],
-        out_specs=[pl.BlockSpec((1, 1), lambda i, j: (i, j)),
-                   pl.BlockSpec((1, 1), lambda i, j: (i, j))],
+        out_specs=[out_spec, out_spec],
         out_shape=[out_shape, out_shape],
         interpret=interpret,
     )(x)
-    return sumsq, sums
+    return sumsq[:, :, 0, 0], sums[:, :, 0, 0]
 
 
 def _stream_moments_kernel(x_ref, sq_ref, s_ref, *, num_blocks):
@@ -97,18 +108,21 @@ def _stream_moments_kernel(x_ref, sq_ref, s_ref, *, num_blocks):
     in-kernel instead of materializing [K, num_blocks] partials."""
     j = pl.program_id(1)
     x = x_ref[...].astype(jnp.float32)            # [kb, br, cols]
-    sq = jnp.sum(x * x, axis=(1, 2))              # [kb]
-    s = jnp.sum(x, axis=(1, 2))
+    # [kb, LANE]: each device's partial broadcast over one lane row
+    sq = jnp.broadcast_to(
+        jnp.sum(jnp.sum(x * x, axis=2), axis=1, keepdims=True), sq_ref.shape)
+    s = jnp.broadcast_to(
+        jnp.sum(jnp.sum(x, axis=2), axis=1, keepdims=True), s_ref.shape)
 
     @pl.when(j == 0)
     def _init():
-        sq_ref[:, 0] = sq
-        s_ref[:, 0] = s
+        sq_ref[...] = sq
+        s_ref[...] = s
 
     @pl.when(j > 0)
     def _accumulate():
-        sq_ref[:, 0] += sq
-        s_ref[:, 0] += s
+        sq_ref[...] += sq
+        s_ref[...] += s
 
 
 def streaming_blocked_moments(x: jax.Array, *, k_block: int,
@@ -120,7 +134,9 @@ def streaming_blocked_moments(x: jax.Array, *, k_block: int,
 
     x: [K, R, C].  Returns ``(sumsq, sums)`` each [K] f32.  Accumulation
     order differs from ``batched_blocked_moments`` + wrapper block-sum by
-    float associativity only (documented-ulp)."""
+    float associativity only (documented-ulp).  Mosaic needs ``k_block``
+    to be a multiple of 8 unless it spans K (``ops.batched_moments`` pads
+    the K-blocks)."""
     k, rows, cols = x.shape
     kb = min(k_block, k)
     if k % kb != 0:
@@ -130,13 +146,13 @@ def streaming_blocked_moments(x: jax.Array, *, k_block: int,
         raise ValueError(f"block_rows {br} must divide rows {rows}")
     nb = rows // br
     grid = (k // kb, nb)
-    out_shape = jax.ShapeDtypeStruct((k, 1), jnp.float32)
+    out_shape = jax.ShapeDtypeStruct((k, LANE), jnp.float32)
+    out_spec = pl.BlockSpec((kb, LANE), lambda i, j: (i, 0))
     sumsq, sums = pl.pallas_call(
         functools.partial(_stream_moments_kernel, num_blocks=nb),
         grid=grid,
         in_specs=[pl.BlockSpec((kb, br, cols), lambda i, j: (i, j, 0))],
-        out_specs=[pl.BlockSpec((kb, 1), lambda i, j: (i, 0)),
-                   pl.BlockSpec((kb, 1), lambda i, j: (i, 0))],
+        out_specs=[out_spec, out_spec],
         out_shape=[out_shape, out_shape],
         interpret=interpret,
     )(x)

@@ -36,8 +36,8 @@ import jax.numpy as jnp
 
 from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention_blocked
-from repro.kernels.grad_norm import (batched_blocked_moments, blocked_sumsq,
-                                     streaming_blocked_moments)
+from repro.kernels.grad_norm import (LANE, batched_blocked_moments,
+                                     blocked_sumsq, streaming_blocked_moments)
 from repro.kernels.ota_aggregate import (ota_aggregate_blocked,
                                          ota_aggregate_streaming)
 
@@ -55,6 +55,54 @@ def _resolve_interpret(interpret: Optional[bool]) -> Union[bool, str]:
 
 
 LANES = 1024  # trailing-dim packing for flat-vector kernels (8x128-aligned)
+SUBLANES = 8  # Mosaic tiles a block's second-to-last dim in multiples of 8
+# bytes of one fp32 input tile of the device-axis kernels.  Double-buffered
+# plus the kernel's temporaries this stays inside the 16 MiB scoped VMEM
+# limit of a v5e core (the dense superpose compiles at (8192, 128) and
+# (4096, 256) tiles, and not at (4096, 1024)).
+TILE_BYTES = 4 * 1024 * 1024
+
+
+def _fit_tile(limit: int, unit_bytes: int, align: int) -> int:
+    """Units per block of a tile with ``unit_bytes`` per unit: ``limit``
+    itself when that tile fits ``TILE_BYTES``, else the largest multiple of
+    ``align`` that fits.  Raises when not even ``align`` units fit: the
+    device axis must then be streamed with a smaller ``k_block``."""
+    if limit * unit_bytes <= TILE_BYTES:
+        return limit
+    units = TILE_BYTES // unit_bytes // align * align
+    if units < align:
+        raise ValueError(
+            f"a {align}-unit tile of {unit_bytes} bytes per unit exceeds the "
+            f"{TILE_BYTES}-byte VMEM tile budget — stream the device axis "
+            "with a smaller k_block")
+    return units
+
+
+def _aligned_k_block(k: int, kb: int) -> int:
+    """``kb`` rounded up to Mosaic's 8-row tiling; a single K-block that
+    spans all of K needs no rounding."""
+    return kb if kb == k else -(-kb // SUBLANES) * SUBLANES
+
+
+def _pad_k_blocks(x: jax.Array, kb: int, kb8: int) -> jax.Array:
+    """Zero-pad every K-block of ``x`` ([K, ...], K a multiple of ``kb``)
+    from ``kb`` to ``kb8`` rows, so a streaming kernel's ``(k_block, ...)``
+    block fits Mosaic's tiling.  The padded rows are zero (and get zero
+    scale), so they add exact zeros to every sum."""
+    if kb8 == kb:
+        return x
+    k = x.shape[0]
+    xb = x.reshape((k // kb, kb) + x.shape[1:])
+    xb = jnp.pad(xb, [(0, 0), (0, kb8 - kb)] + [(0, 0)] * (x.ndim - 1))
+    return xb.reshape((k // kb * kb8,) + x.shape[1:])
+
+
+def _unpad_k_blocks(x: jax.Array, k: int, kb: int, kb8: int) -> jax.Array:
+    """Inverse of ``_pad_k_blocks`` on a per-device [K'] result."""
+    if kb8 == kb:
+        return x
+    return x.reshape(k // kb, kb8)[:, :kb].reshape(k)
 
 
 def _pack_flat(x: jax.Array, lanes: int = LANES,
@@ -124,9 +172,17 @@ def batched_moments(g: jax.Array, *, block_rows: int = 256,
             raise ValueError(f"k_block {kb} must divide K {g.shape[0]}")
         if interpret == "ref":
             return ref.streaming_moments_ref(g, kb)
-        g3, _, br = _pack_flat_batched(g, block_rows=block_rows)
-        return streaming_blocked_moments(g3, k_block=kb, block_rows=br,
-                                         interpret=interpret)
+        k, n = g.shape
+        kb8 = _aligned_k_block(k, kb)
+        g = _pad_k_blocks(g, kb, kb8)
+        rows = -(-n // LANES)
+        br = _fit_tile(min(block_rows, rows), kb8 * LANES * 4, SUBLANES)
+        g3, _, br = _pack_flat_batched(g, block_rows=br)
+        sumsq, sums = streaming_blocked_moments(g3, k_block=kb8,
+                                                block_rows=br,
+                                                interpret=interpret)
+        return (_unpad_k_blocks(sumsq, k, kb, kb8),
+                _unpad_k_blocks(sums, k, kb, kb8))
     if interpret == "ref":
         return ref.batched_moments_ref(g)
     g3, _, br = _pack_flat_batched(g, block_rows=block_rows)
@@ -159,7 +215,8 @@ def ota_superpose(g: jax.Array, scale: jax.Array, noise: jax.Array, a, *,
     an (N-block, K-block) grid whose output tile is the fp32 accumulator
     (oracle: sequential ``lax.scan`` over K-blocks), so VMEM holds
     (k_block, block) tiles instead of full-K columns.  ``None`` keeps the
-    dense kernel bitwise-unchanged.
+    dense kernel bitwise-unchanged.  The N-block is ``block`` shrunk until
+    the ``(K or k_block, N-block)`` tile fits ``TILE_BYTES`` of VMEM.
     """
     interpret = _resolve_interpret(interpret)
     a = jnp.asarray(a, jnp.float32)
@@ -173,17 +230,24 @@ def ota_superpose(g: jax.Array, scale: jax.Array, noise: jax.Array, a, *,
     elif interpret == "ref":
         return ref.ota_superpose_ref(g, scale, noise, a, pre=pre)
     k, n = g.shape
-    pad_rows = -(-n // block) * block - n
-    if pad_rows:
-        g = jnp.concatenate([g, jnp.zeros((k, pad_rows), g.dtype)], axis=1)
-        noise = jnp.concatenate([noise, jnp.zeros((pad_rows,), noise.dtype)])
-    if k_block is not None:
-        y = ota_aggregate_streaming(g, scale.astype(jnp.float32), noise, a,
-                                    k_block=min(k_block, k), block=block,
-                                    interpret=interpret, pre=pre)
+    scale = scale.astype(jnp.float32)
+    if k_block is None:
+        blk = _fit_tile(block, k * 4, LANE)
     else:
-        y = ota_aggregate_blocked(g, scale.astype(jnp.float32), noise, a,
-                                  block=block, interpret=interpret, pre=pre)
+        kb8 = _aligned_k_block(k, kb)
+        scale = _pad_k_blocks(scale, kb, kb8)
+        g = _pad_k_blocks(g, kb, kb8)
+        blk = _fit_tile(block, kb8 * 4, LANE)
+    pad_cols = -(-n // blk) * blk - n
+    if pad_cols:
+        g = jnp.pad(g, ((0, 0), (0, pad_cols)))
+        noise = jnp.pad(noise, (0, pad_cols))
+    if k_block is not None:
+        y = ota_aggregate_streaming(g, scale, noise, a, k_block=kb8,
+                                    block=blk, interpret=interpret, pre=pre)
+    else:
+        y = ota_aggregate_blocked(g, scale, noise, a, block=blk,
+                                  interpret=interpret, pre=pre)
     return y[:n]
 
 

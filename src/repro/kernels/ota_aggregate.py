@@ -19,6 +19,11 @@ implementation reads the K gradients once for the scale, once for the sum and
 touches y three times; this kernel is the paper's entire eq. (10) as a single
 memory-bound sweep.
 
+VMEM: the dense kernel holds a double-buffered ``(K, block)`` tile, so
+``ops.ota_superpose`` shrinks the N-block as K grows to stay inside the
+scoped VMEM limit; the per-column K-way sums, and so the result, do not
+depend on the N-block.
+
 Target: TPU VPU (8x128 lanes); validated on CPU via interpret=True against
 ``ref.ota_superpose_ref``.
 """

@@ -46,10 +46,7 @@ def _selective_scan_kernel(u_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, h_scr,
         dbu = (dt[t] * u[t])[:, None] * bmat[t][None, :]       # [bd, N]
         h = da * h + dbu
         y_t = jnp.sum(h * cmat[t][None, :], axis=1)            # [bd]
-        # dslice(0, 1) instead of a bare 0: older pallas discharge rules
-        # reject scalar-int indices mixed with dynamic slices
-        pl.store(y_ref, (pl.dslice(0, 1), pl.dslice(t, 1), slice(None)),
-                 y_t[None, None, :])
+        y_ref[0, pl.ds(t, 1), :] = y_t[None, :]
         return h
 
     h = jax.lax.fori_loop(0, chunk, body, h_scr[...])
