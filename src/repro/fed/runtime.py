@@ -587,6 +587,11 @@ _fence_leaf = ota.fence_leaf
 _fusion_fence = ota.fusion_fence
 
 
+# Each stage of the round runs under a jax.named_scope (local_step,
+# aggregate, server_update, fading_refresh), so its compiled ops carry the
+# stage in their op_name metadata for device traces; the arithmetic is
+# unchanged.
+@jax.named_scope("local_step")
 def _local_transmit(cfg: FLConfig, grad_fn: GradFn, params, batch,
                     corr=None, dev_state=None) -> PyTree:
     """The quantity each device hands to the scheme's transform: its local
@@ -723,122 +728,132 @@ def _round_math(cfg: FLConfig, sch, opt, grad_fn: GradFn, params, opt_state,
         active = stacked = _local_transmit(cfg, grad_fn, params, batch,
                                            corr, dev_active)
         b_air = b_eff
-    if mask is not None:
-        # fence the gradient stack so the aggregation below consumes a
-        # materialized [K, ...] value: without it XLA fuses the aggregate's
-        # K-way reductions into the (round-shape-dependent) gradient
-        # producer, and the dense-masked and active-gather programs — whose
-        # reduction TERMS are identical, masked devices contributing exact
-        # zeros — would associate them differently, breaking the bitwise
-        # gather contract.  Full-participation rounds (the golden-pinned
-        # default) never take this branch.
-        stacked = _fusion_fence(stacked)
-    if mask is not None and sch.baseline:
-        # baseline schemes bypass the channel (plain mean on every backend),
-        # so the mask cannot reach them through b_eff — average over the
-        # participants only, or the ideal reference would silently use all K
-        # devices while the diagnostics report a partial cohort
-        w = mask / jnp.maximum(jnp.sum(mask), 1.0)
-        y = jax.tree_util.tree_map(
-            lambda l: jnp.tensordot(w, l.astype(jnp.float32), axes=(0, 0)),
-            stacked)
-    else:
-        ocfg = ota.OTAConfig(scheme=cfg.scheme, a=a_eff,
-                             noise_var=noise_var,
-                             grad_bound=grad_bound, backend=cfg.backend)
-        y = ota.aggregate(ocfg, stacked, h, b_eff,
-                          jax.random.fold_in(key, t), h_hat=h_hat)
-    # one stats pass feeds BOTH diagnostics (grad norms and the eq. 8
-    # transmit-energy accounting); the aggregate above keeps its own internal
-    # stats — folding the two would need aggregate() to return them.  Under
-    # active_gather the stats cover the participants only (the scattered
-    # zero rows are channel inputs, not computed gradients): the grad-norm
-    # diagnostics shrink to the active set, while tx_energy is unchanged
-    # (masked devices spent nothing — their dense energy terms were b_k = 0)
-    stats = schemes.compute_stats(active, sch, batched=True)
-    norms = jnp.sqrt(stats.sq_norm)
-    tx = schemes.transmit_energy(sch, stats, b_air, grad_bound,
-                                 None if idx is not None else mask)
-    if idx is not None:
-        # scatter the active set's energies back to the [K] layout (masked
-        # devices spent exactly 0) and fence, so the eq.-8 total below runs
-        # the same [K]-way sum as the dense masked round (per-device
-        # energies can still carry ulp noise: [m]-row reductions vectorize
-        # differently than [K]-row ones)
-        tx = jnp.zeros((cfg.num_devices,), tx.dtype).at[idx].set(tx)
-    if mask is not None:
-        tx = _fusion_fence(tx)
-    # total transmit energy sum_k b_k^2 ||x_k||^2 (eq. 8 budget) via the
-    # scheme's analytic accounting; masked-out devices spend nothing.  A
-    # second OTA slot adds its own eq.-8 term below.
-    tx_energy = jnp.sum(tx)
+    with jax.named_scope("aggregate"):
+        if mask is not None:
+            # fence the gradient stack so the aggregation below consumes a
+            # materialized [K, ...] value: without it XLA fuses the
+            # aggregate's K-way reductions into the (round-shape-dependent)
+            # gradient producer, and the dense-masked and active-gather
+            # programs — whose reduction TERMS are identical, masked devices
+            # contributing exact zeros — would associate them differently,
+            # breaking the bitwise gather contract.  Full-participation
+            # rounds (the golden-pinned default) never take this branch.
+            stacked = _fusion_fence(stacked)
+        if mask is not None and sch.baseline:
+            # baseline schemes bypass the channel (plain mean on every
+            # backend), so the mask cannot reach them through b_eff — average
+            # over the participants only, or the ideal reference would
+            # silently use all K devices while the diagnostics report a
+            # partial cohort
+            w = mask / jnp.maximum(jnp.sum(mask), 1.0)
+            y = jax.tree_util.tree_map(
+                lambda l: jnp.tensordot(w, l.astype(jnp.float32),
+                                        axes=(0, 0)),
+                stacked)
+        else:
+            ocfg = ota.OTAConfig(scheme=cfg.scheme, a=a_eff,
+                                 noise_var=noise_var,
+                                 grad_bound=grad_bound, backend=cfg.backend)
+            y = ota.aggregate(ocfg, stacked, h, b_eff,
+                              jax.random.fold_in(key, t), h_hat=h_hat)
+        # one stats pass feeds BOTH diagnostics (grad norms and the eq. 8
+        # transmit-energy accounting); the aggregate above keeps its own
+        # internal stats — folding the two would need aggregate() to return
+        # them.  Under active_gather the stats cover the participants only
+        # (the scattered zero rows are channel inputs, not computed
+        # gradients): the grad-norm diagnostics shrink to the active set,
+        # while tx_energy is unchanged (masked devices spent nothing — their
+        # dense energy terms were b_k = 0)
+        stats = schemes.compute_stats(active, sch, batched=True)
+        norms = jnp.sqrt(stats.sq_norm)
+        tx = schemes.transmit_energy(sch, stats, b_air, grad_bound,
+                                     None if idx is not None else mask)
+        if idx is not None:
+            # scatter the active set's energies back to the [K] layout
+            # (masked devices spent exactly 0) and fence, so the eq.-8 total
+            # below runs the same [K]-way sum as the dense masked round
+            # (per-device energies can still carry ulp noise: [m]-row
+            # reductions vectorize differently than [K]-row ones)
+            tx = jnp.zeros((cfg.num_devices,), tx.dtype).at[idx].set(tx)
+        if mask is not None:
+            tx = _fusion_fence(tx)
+        # total transmit energy sum_k b_k^2 ||x_k||^2 (eq. 8 budget) via the
+        # scheme's analytic accounting; masked-out devices spend nothing.  A
+        # second OTA slot adds its own eq.-8 term below.
+        tx_energy = jnp.sum(tx)
 
-    new_client_state = client_state
-    if alg.stateful:
-        tmap = jax.tree_util.tree_map
-        hlr = cfg.local_steps * cfg.local_lr
-        dev_new = dev_state
-        dev_new_active = dev_active
-        if alg.has_state:
-            # the state transition sees the round's transmitted statistic
-            # (``active``: grad for H = 1, the accumulated delta otherwise)
-            dev_new_active = alg.update_state(cp, hlr, dev_active, srv_state,
-                                              active)
-            if idx is not None:
-                dev_new = tmap(lambda full, act: full.at[idx].set(act),
-                               dev_state, dev_new_active)
-            elif mask is not None:
-                # a masked device did not participate: its state must not
-                # move (the raw transition still feeds slot 2 below, where
-                # b_eff = 0 already silences the masked rows)
-                keep = mask.astype(bool)
-                dev_new = tmap(
-                    lambda new, old: jnp.where(
-                        keep.reshape((-1,) + (1,) * (new.ndim - 1)),
-                        new, old), dev_new_active, dev_state)
-            else:
-                dev_new = dev_new_active
-        srv_new = srv_state
-        if alg.num_slots == 2:
-            # ---- the second OTA transmission slot -------------------------
-            # same channel realization h/b_eff/a_eff (the slots are
-            # consecutive symbols of one coherence block), its own
-            # normalization scheme, an independent noise draw, and its own
-            # eq.-8 energy.  The server learns its state from the DE-GAINED
-            # aggregate: y2 / (a sum h_hat b) is approximately the
-            # participant-mean transmitted statistic.
-            sch2 = schemes.get(cfg.client.variate_scheme)
-            x2_active = alg.variate_stat(cp, dev_active, dev_new_active,
-                                         srv_state, active)
-            if idx is not None:
-                x2 = tmap(lambda l: jnp.zeros(
-                    (cfg.num_devices,) + l.shape[1:], l.dtype).at[idx].set(l),
-                    x2_active)
-            else:
-                x2 = x2_active
-            if mask is not None:
-                x2 = _fusion_fence(x2)
-            stats2 = schemes.compute_stats(x2_active, sch2, batched=True)
-            tx2 = schemes.transmit_energy(sch2, stats2, b_air, grad_bound,
-                                          None if idx is not None else mask)
-            if idx is not None:
-                tx2 = jnp.zeros((cfg.num_devices,), tx2.dtype).at[idx].set(tx2)
-            if mask is not None:
-                tx2 = _fusion_fence(tx2)
-            tx_energy = tx_energy + jnp.sum(tx2)
-            ocfg2 = ota.OTAConfig(scheme=cfg.client.variate_scheme, a=a_eff,
-                                  noise_var=noise_var,
-                                  grad_bound=grad_bound, backend=cfg.backend)
-            key2 = jax.random.fold_in(jax.random.fold_in(key, t), _SLOT_SALT)
-            y2 = ota.aggregate(ocfg2, x2, h, b_eff, key2, h_hat=h_hat)
-            gain = a_eff * jnp.sum(h_hat * b_eff)
-            y2_hat = tmap(lambda l: l / jnp.maximum(gain, schemes.EPS), y2)
-            # |participants|/K scales the server variate step (SCAFFOLD's
-            # m/K); an empty round has gain = 0 AND frac = 0 — srv holds
-            frac = (jnp.sum(mask) / cfg.num_devices if mask is not None
-                    else jnp.asarray(1.0, jnp.float32))
-            srv_new = alg.apply_variate(cp, srv_state, y2_hat, frac)
-        new_client_state = {"dev": dev_new, "srv": srv_new}
+        new_client_state = client_state
+        if alg.stateful:
+            tmap = jax.tree_util.tree_map
+            hlr = cfg.local_steps * cfg.local_lr
+            dev_new = dev_state
+            dev_new_active = dev_active
+            if alg.has_state:
+                # the state transition sees the round's transmitted
+                # statistic (``active``: grad for H = 1, the accumulated
+                # delta otherwise)
+                dev_new_active = alg.update_state(cp, hlr, dev_active,
+                                                  srv_state, active)
+                if idx is not None:
+                    dev_new = tmap(lambda full, act: full.at[idx].set(act),
+                                   dev_state, dev_new_active)
+                elif mask is not None:
+                    # a masked device did not participate: its state must
+                    # not move (the raw transition still feeds slot 2 below,
+                    # where b_eff = 0 already silences the masked rows)
+                    keep = mask.astype(bool)
+                    dev_new = tmap(
+                        lambda new, old: jnp.where(
+                            keep.reshape((-1,) + (1,) * (new.ndim - 1)),
+                            new, old), dev_new_active, dev_state)
+                else:
+                    dev_new = dev_new_active
+            srv_new = srv_state
+            if alg.num_slots == 2:
+                # ---- the second OTA transmission slot ---------------------
+                # same channel realization h/b_eff/a_eff (the slots are
+                # consecutive symbols of one coherence block), its own
+                # normalization scheme, an independent noise draw, and its
+                # own eq.-8 energy.  The server learns its state from the
+                # DE-GAINED aggregate: y2 / (a sum h_hat b) is approximately
+                # the participant-mean transmitted statistic.
+                sch2 = schemes.get(cfg.client.variate_scheme)
+                x2_active = alg.variate_stat(cp, dev_active, dev_new_active,
+                                             srv_state, active)
+                if idx is not None:
+                    x2 = tmap(lambda l: jnp.zeros(
+                        (cfg.num_devices,) + l.shape[1:],
+                        l.dtype).at[idx].set(l), x2_active)
+                else:
+                    x2 = x2_active
+                if mask is not None:
+                    x2 = _fusion_fence(x2)
+                stats2 = schemes.compute_stats(x2_active, sch2, batched=True)
+                tx2 = schemes.transmit_energy(
+                    sch2, stats2, b_air, grad_bound,
+                    None if idx is not None else mask)
+                if idx is not None:
+                    tx2 = jnp.zeros((cfg.num_devices,),
+                                    tx2.dtype).at[idx].set(tx2)
+                if mask is not None:
+                    tx2 = _fusion_fence(tx2)
+                tx_energy = tx_energy + jnp.sum(tx2)
+                ocfg2 = ota.OTAConfig(scheme=cfg.client.variate_scheme,
+                                      a=a_eff, noise_var=noise_var,
+                                      grad_bound=grad_bound,
+                                      backend=cfg.backend)
+                key2 = jax.random.fold_in(jax.random.fold_in(key, t),
+                                          _SLOT_SALT)
+                y2 = ota.aggregate(ocfg2, x2, h, b_eff, key2, h_hat=h_hat)
+                gain = a_eff * jnp.sum(h_hat * b_eff)
+                y2_hat = tmap(
+                    lambda l: l / jnp.maximum(gain, schemes.EPS), y2)
+                # |participants|/K scales the server variate step (SCAFFOLD's
+                # m/K); an empty round has gain = 0 AND frac = 0 — srv holds
+                frac = (jnp.sum(mask) / cfg.num_devices if mask is not None
+                        else jnp.asarray(1.0, jnp.float32))
+                srv_new = alg.apply_variate(cp, srv_state, y2_hat, frac)
+            new_client_state = {"dev": dev_new, "srv": srv_new}
 
     diag_core = {
         "grad_norm_mean": jnp.mean(norms),
@@ -852,6 +867,7 @@ def _round_math(cfg: FLConfig, sch, opt, grad_fn: GradFn, params, opt_state,
     return new_params, new_opt_state, new_client_state, diag
 
 
+@jax.named_scope("server_update")
 def _round_tail(cfg, sch, opt, params, opt_state, y, mask, eta0, t,
                 diag_core, a_eff, h, h_hat, b_eff):
     """Post-aggregation tail shared by the dense and streaming rounds:
@@ -1181,34 +1197,39 @@ def _round_math_streaming(cfg: FLConfig, sch, opt, grad_fn: GradFn, params,
             new_carry = new_carry[:4] + (txsum + jnp.sum(tx2), oc2)
         return new_carry, ys
 
-    carry_out, ys_out = _scan_stream_blocks(cfg, body, carry0, xs)
-    oc, nsum, nmin, nmax, txsum = carry_out[:5]
-    y = ota.streaming_finish(ocfg, oc, template, a_eff,
-                             jax.random.fold_in(key, t),
-                             noise_var=noise_var,
-                             num_devices=1.0 if weighted else float(s))
-    new_client_state = client_state
-    if alg.stateful:
-        dev_new = dev_state
-        if alg.has_state:
-            flat_new = tmap(lambda l: l.reshape((s,) + l.shape[2:]), ys_out)
-            if idx is not None:
-                dev_new = tmap(lambda full, fl: full.at[idx].set(fl),
-                               dev_state, flat_new)
-            else:
-                dev_new = flat_new
-        srv_new = srv_state
-        if two_slot:
-            key2 = jax.random.fold_in(jax.random.fold_in(key, t), _SLOT_SALT)
-            y2 = ota.streaming_finish(ocfg2, carry_out[5], template, a_eff,
-                                      key2, noise_var=noise_var,
-                                      num_devices=float(s))
-            gain = a_eff * ksum(h_hat * b_eff)
-            y2_hat = tmap(lambda l: l / jnp.maximum(gain, schemes.EPS), y2)
-            frac = (jnp.sum(mask) / cfg.num_devices if mask is not None
-                    else jnp.asarray(1.0, jnp.float32))
-            srv_new = alg.apply_variate(cp, srv_state, y2_hat, frac)
-        new_client_state = {"dev": dev_new, "srv": srv_new}
+    with jax.named_scope("aggregate"):
+        carry_out, ys_out = _scan_stream_blocks(cfg, body, carry0, xs)
+        oc, nsum, nmin, nmax, txsum = carry_out[:5]
+        y = ota.streaming_finish(ocfg, oc, template, a_eff,
+                                 jax.random.fold_in(key, t),
+                                 noise_var=noise_var,
+                                 num_devices=1.0 if weighted else float(s))
+        new_client_state = client_state
+        if alg.stateful:
+            dev_new = dev_state
+            if alg.has_state:
+                flat_new = tmap(lambda l: l.reshape((s,) + l.shape[2:]),
+                                ys_out)
+                if idx is not None:
+                    dev_new = tmap(lambda full, fl: full.at[idx].set(fl),
+                                   dev_state, flat_new)
+                else:
+                    dev_new = flat_new
+            srv_new = srv_state
+            if two_slot:
+                key2 = jax.random.fold_in(jax.random.fold_in(key, t),
+                                          _SLOT_SALT)
+                y2 = ota.streaming_finish(ocfg2, carry_out[5], template,
+                                          a_eff, key2, noise_var=noise_var,
+                                          num_devices=float(s))
+                gain = a_eff * ksum(h_hat * b_eff)
+                y2_hat = tmap(
+                    lambda l: l / jnp.maximum(gain, schemes.EPS), y2)
+                frac = (jnp.sum(mask) / cfg.num_devices if mask is not None
+                        else jnp.asarray(1.0, jnp.float32))
+                srv_new = alg.apply_variate(cp, srv_state, y2_hat, frac)
+            new_client_state = {"dev": dev_new, "srv": srv_new}
+
     diag_core = {
         "grad_norm_mean": nsum / s,
         "grad_norm_min": nmin,
@@ -1221,6 +1242,7 @@ def _round_math_streaming(cfg: FLConfig, sch, opt, grad_fn: GradFn, params,
     return new_params, new_opt_state, new_client_state, diag
 
 
+@jax.named_scope("fading_refresh")
 def _fading_refresh(cfg: FLConfig, model_dim: int, eff_gain, chan_key, t,
                     fad_state, over: Optional[BatchAxes] = None):
     """Time-varying channel (beyond the paper, which holds h_k fixed): step
@@ -1532,61 +1554,78 @@ def run(cfg: FLConfig, state: FLState, grad_fn: GradFn,
     if block_batch_provider is not None and cfg.k_block is None:
         raise ValueError("block_batch_provider streams per-K-block batches "
                          "inside the round scan; set cfg.k_block")
-    opt = server_optimizer(cfg)
-    if state.opt_state is None:
-        # states built before the server-optimizer axis (or restored from
-        # old checkpoints): initialize, with step = rounds already taken so
-        # Adam bias correction matches an unbroken run
-        state.opt_state = opt.init(state.params)._replace(
-            step=jnp.asarray(state.round, jnp.int32))
-    opt_state = state.opt_state
-    alg = clientlib.get(cfg.client.algo)
-    if alg.stateful and state.client_state is None:
-        # states built before the client-algorithm axis (or restored from
-        # pre-registry checkpoints): zero state, like a fresh setup()
-        state.client_state = clientlib.init_state(cfg.client, state.params,
-                                                  cfg.num_devices)
-    client_state = (None if state.client_state is None else
-                    jax.tree_util.tree_map(jnp.asarray, state.client_state))
-    key = jax.random.PRNGKey(cfg.seed + 1)
-    h = jnp.asarray(state.h, jnp.float32)
-    # perfect CSI is structural: h_hat = None makes the estimate alias h's
-    # traced value exactly (leafless carry, hard-zero csi_gain_err)
-    perfect_csi = cfg.channel.csi_error == 0.0
-    h_hat_np = state.h_hat if state.h_hat is not None else state.h
-    h_hat = None if perfect_csi else jnp.asarray(h_hat_np, jnp.float32)
-    b = jnp.asarray(state.b, jnp.float32)
-    a = jnp.asarray(state.a, jnp.float32)
-    eta0 = jnp.asarray(state.eta0, jnp.float32)
-    model = chl.get(cfg.channel.model)
-    time_varying = cfg.channel.time_varying()
-    chan_key = jax.random.PRNGKey(cfg.seed + 2)
-    eff_gain = jnp.zeros((), jnp.float32)
-    fad_state = None
-    if model.has_state:
-        if state.fad_state is None:
-            raise ValueError(
-                f"channel model {cfg.channel.model!r} threads a persistent "
-                "fading state; FLState.fad_state is unset — build the state "
-                "via setup()")
-        fad_state = jnp.asarray(state.fad_state, jnp.float32)
-    # geometry-heterogeneous per-device scales ride through the over lane
-    # (None — the homogeneous default — keeps the baked-config trace)
-    over = None
-    if state.scale is not None:
-        over = BatchAxes(
-            rayleigh_scale=jnp.asarray(state.scale, jnp.float32))
-    if time_varying:
-        if state.model_dim <= 0:
-            raise ValueError("a time-varying channel re-solves Problem 3 "
-                             "with the real model dimension; "
-                             "FLState.model_dim is unset — build the state "
-                             "via setup()")
-        # the DESIGNED effective gain: what the server set on its estimate
-        eff_gain = jnp.asarray(
-            state.a * float(np.sum(np.asarray(h_hat_np, np.float64)
-                                   * np.asarray(state.b, np.float64))),
-            jnp.float32)
+    with obsprof.span("runtime.prologue"):
+        opt = server_optimizer(cfg)
+        if state.opt_state is None:
+            # states built before the server-optimizer axis (or restored from
+            # old checkpoints): initialize, with step = rounds already taken so
+            # Adam bias correction matches an unbroken run
+            state.opt_state = opt.init(state.params)._replace(
+                step=jnp.asarray(state.round, jnp.int32))
+        opt_state = state.opt_state
+        alg = clientlib.get(cfg.client.algo)
+        if alg.stateful and state.client_state is None:
+            # states built before the client-algorithm axis (or restored from
+            # pre-registry checkpoints): zero state, like a fresh setup()
+            state.client_state = clientlib.init_state(cfg.client, state.params,
+                                                      cfg.num_devices)
+        client_state = (
+            None if state.client_state is None else
+            jax.tree_util.tree_map(jnp.asarray, state.client_state))
+        key = jax.random.PRNGKey(cfg.seed + 1)
+        h = jnp.asarray(state.h, jnp.float32)
+        # perfect CSI is structural: h_hat = None makes the estimate alias h's
+        # traced value exactly (leafless carry, hard-zero csi_gain_err)
+        perfect_csi = cfg.channel.csi_error == 0.0
+        h_hat_np = state.h_hat if state.h_hat is not None else state.h
+        h_hat = None if perfect_csi else jnp.asarray(h_hat_np, jnp.float32)
+        b = jnp.asarray(state.b, jnp.float32)
+        a = jnp.asarray(state.a, jnp.float32)
+        eta0 = jnp.asarray(state.eta0, jnp.float32)
+        model = chl.get(cfg.channel.model)
+        time_varying = cfg.channel.time_varying()
+        chan_key = jax.random.PRNGKey(cfg.seed + 2)
+        eff_gain = jnp.zeros((), jnp.float32)
+        fad_state = None
+        if model.has_state:
+            if state.fad_state is None:
+                raise ValueError(
+                    f"channel model {cfg.channel.model!r} threads a "
+                    "persistent fading state; FLState.fad_state is unset — "
+                    "build the state via setup()")
+            fad_state = jnp.asarray(state.fad_state, jnp.float32)
+        # geometry-heterogeneous per-device scales ride through the over lane
+        # (None — the homogeneous default — keeps the baked-config trace)
+        over = None
+        if state.scale is not None:
+            over = BatchAxes(
+                rayleigh_scale=jnp.asarray(state.scale, jnp.float32))
+        if time_varying:
+            if state.model_dim <= 0:
+                raise ValueError("a time-varying channel re-solves "
+                                 "Problem 3 with the real model dimension; "
+                                 "FLState.model_dim is unset — build the "
+                                 "state via setup()")
+            # the DESIGNED effective gain: what the server set on its estimate
+            eff_gain = jnp.asarray(
+                state.a * float(np.sum(np.asarray(h_hat_np, np.float64)
+                                       * np.asarray(state.b, np.float64))),
+                jnp.float32)
+        t0 = state.round
+        if driver == "python":
+            round_step = make_round_step(cfg, grad_fn, block_batch_provider)
+            fading_refresh = _make_fading_refresh(cfg, state.model_dim)
+            params = state.params
+        else:
+            run_chunk = _make_run_chunk(cfg, grad_fn, state.model_dim,
+                                        block_batch_provider)
+            # params, optimizer state, and client state are donated
+            # chunk-to-chunk; copy once so the CALLER's pytrees (often
+            # reused across runs, e.g. the benchmark experiments) survive
+            params = jax.tree_util.tree_map(jnp.copy, state.params)
+            opt_state = jax.tree_util.tree_map(jnp.copy, opt_state)
+            client_state = (None if client_state is None else
+                            jax.tree_util.tree_map(jnp.copy, client_state))
 
     hist: Dict[str, List] = {"round": [], "eval_round": []}
     for k in DIAG_KEYS:
@@ -1596,107 +1635,109 @@ def run(cfg: FLConfig, state: FLState, grad_fn: GradFn,
 
     def record_eval(params, t):
         nonlocal eval_keys
-        metrics = eval_fn(params)
-        eval_keys = _locked_eval_keys(metrics, eval_keys, t)
-        for mk in eval_keys:
-            hist.setdefault(mk, []).append(metrics[mk])
-        hist["eval_round"].append(t)
-        if recorder is not None:
-            recorder.on_eval(t, {mk: float(metrics[mk]) for mk in eval_keys})
+        with obsprof.span("runtime.eval"):
+            metrics = eval_fn(params)
+            eval_keys = _locked_eval_keys(metrics, eval_keys, t)
+            for mk in eval_keys:
+                hist.setdefault(mk, []).append(metrics[mk])
+            hist["eval_round"].append(t)
+            if recorder is not None:
+                recorder.on_eval(t, {mk: float(metrics[mk])
+                                     for mk in eval_keys})
 
-    t0 = state.round
     if driver == "python":
-        round_step = make_round_step(cfg, grad_fn, block_batch_provider)
-        fading_refresh = _make_fading_refresh(cfg, state.model_dim)
-        params = state.params
         for chunk_i, t in enumerate(range(t0 + 1, t0 + num_rounds + 1)):
-            if recorder is not None:
-                tr0 = dict(TRACE_COUNTS)
-                wt0 = time.perf_counter()
             with obsprof.annotate_chunk(chunk_i):
+                if recorder is not None:
+                    tr0 = dict(TRACE_COUNTS)
+                    wt0 = time.perf_counter()
                 if time_varying:
-                    h, h_hat_t, b, a, fad_state = fading_refresh(
-                        eff_gain, chan_key, jnp.asarray(t), fad_state, over)
+                    with obsprof.span("runtime.dispatch"):
+                        h, h_hat_t, b, a, fad_state = fading_refresh(
+                            eff_gain, chan_key, jnp.asarray(t), fad_state,
+                            over)
                     h_hat = None if perfect_csi else h_hat_t
-                batch = (None if block_batch_provider is not None
-                         else batch_provider(t))
-                params, opt_state, client_state, diag = round_step(
-                    params, opt_state, client_state, batch, h, h_hat, b, a,
-                    eta0, jnp.asarray(t), key)
-            hist["round"].append(t)
-            for k in DIAG_KEYS:
-                hist[k].append(float(diag[k]))
-            if recorder is not None:
-                # the python driver's 'chunk' is one round: one (or, under a
-                # time-varying channel, two) dispatches
-                recorder.on_chunk(
-                    chunk_i, [t], {k: np.asarray([hist[k][-1]])
-                                   for k in DIAG_KEYS},
-                    wall_time_s=time.perf_counter() - wt0,
-                    dispatches=2 if time_varying else 1,
-                    retraces=trace_deltas(tr0),
-                    rss_mb=obsprof.rss_mb())
-            if eval_fn is not None and (t % eval_every == 0 or t == 1):
-                record_eval(params, t)
+                with obsprof.span("runtime.batches"):
+                    batch = (None if block_batch_provider is not None
+                             else batch_provider(t))
+                with obsprof.span("runtime.dispatch"):
+                    params, opt_state, client_state, diag = round_step(
+                        params, opt_state, client_state, batch, h, h_hat, b,
+                        a, eta0, jnp.asarray(t), key)
+                hist["round"].append(t)
+                with obsprof.span("runtime.history_get"):
+                    for k in DIAG_KEYS:
+                        hist[k].append(float(diag[k]))
+                if recorder is not None:
+                    # the python driver's 'chunk' is one round: one (or,
+                    # under a time-varying channel, two) dispatches
+                    recorder.on_chunk(
+                        chunk_i, [t], {k: np.asarray([hist[k][-1]])
+                                       for k in DIAG_KEYS},
+                        wall_time_s=time.perf_counter() - wt0,
+                        dispatches=2 if time_varying else 1,
+                        retraces=trace_deltas(tr0),
+                        rss_mb=obsprof.rss_mb())
+                if eval_fn is not None and (t % eval_every == 0 or t == 1):
+                    record_eval(params, t)
     else:
-        run_chunk = _make_run_chunk(cfg, grad_fn, state.model_dim,
-                                    block_batch_provider)
-        # params, optimizer state, and client state are donated
-        # chunk-to-chunk; copy once so the CALLER's pytrees (often reused
-        # across runs, e.g. the benchmark experiments) survive
-        params = jax.tree_util.tree_map(jnp.copy, state.params)
-        opt_state = jax.tree_util.tree_map(jnp.copy, opt_state)
-        client_state = (None if client_state is None else
-                        jax.tree_util.tree_map(jnp.copy, client_state))
         for chunk_i, ts in enumerate(_plan_chunks(
                 t0, num_rounds,
                 eval_every if eval_fn is not None else None, chunk_size)):
-            if recorder is not None:
-                tr0 = dict(TRACE_COUNTS)
-                wt0 = time.perf_counter()
             with obsprof.annotate_chunk(chunk_i):
-                if block_batch_provider is not None:
-                    batches = None     # drawn per (round, K-block) in-scan
-                else:
-                    batches = (chunk_batch_provider(ts) if chunk_batch_provider
-                               else _stack_batches(batch_provider, ts))
-                (params, opt_state, client_state, h, h_hat, b, a, fad_state,
-                 chunk_hist) = run_chunk(
-                     params, opt_state, client_state, h, h_hat, b, a, eta0,
-                     key, chan_key, eff_gain, fad_state, over,
-                     jnp.asarray(ts, jnp.int32), batches)
-                chunk_hist = jax.device_get(chunk_hist)   # ONE sync per chunk
-            hist["round"].extend(ts)
-            for k in DIAG_KEYS:
-                hist[k].extend(np.asarray(chunk_hist[k]).astype(float).tolist())
-            if recorder is not None:
-                recorder.on_chunk(
-                    chunk_i, list(ts),
-                    {k: np.asarray(chunk_hist[k]) for k in DIAG_KEYS},
-                    wall_time_s=time.perf_counter() - wt0,
-                    dispatches=1,
-                    retraces=trace_deltas(tr0),
-                    rss_mb=obsprof.rss_mb())
-            t_end = ts[-1]
-            if eval_fn is not None and (t_end % eval_every == 0 or t_end == 1):
-                record_eval(params, t_end)
+                if recorder is not None:
+                    tr0 = dict(TRACE_COUNTS)
+                    wt0 = time.perf_counter()
+                with obsprof.span("runtime.batches"):
+                    if block_batch_provider is not None:
+                        batches = None  # drawn per (round, K-block) in-scan
+                    else:
+                        batches = (chunk_batch_provider(ts)
+                                   if chunk_batch_provider
+                                   else _stack_batches(batch_provider, ts))
+                with obsprof.span("runtime.dispatch"):
+                    (params, opt_state, client_state, h, h_hat, b, a,
+                     fad_state, chunk_hist) = run_chunk(
+                         params, opt_state, client_state, h, h_hat, b, a,
+                         eta0, key, chan_key, eff_gain, fad_state, over,
+                         jnp.asarray(ts, jnp.int32), batches)
+                with obsprof.span("runtime.history_get"):
+                    # ONE sync per chunk
+                    chunk_hist = jax.device_get(chunk_hist)
+                hist["round"].extend(ts)
+                for k in DIAG_KEYS:
+                    hist[k].extend(
+                        np.asarray(chunk_hist[k]).astype(float).tolist())
+                if recorder is not None:
+                    recorder.on_chunk(
+                        chunk_i, list(ts),
+                        {k: np.asarray(chunk_hist[k]) for k in DIAG_KEYS},
+                        wall_time_s=time.perf_counter() - wt0,
+                        dispatches=1,
+                        retraces=trace_deltas(tr0),
+                        rss_mb=obsprof.rss_mb())
+                t_end = ts[-1]
+                if eval_fn is not None and (t_end % eval_every == 0
+                                            or t_end == 1):
+                    record_eval(params, t_end)
 
-    state.params = params
-    state.opt_state = opt_state
-    if client_state is not None:
-        state.client_state = client_state
-    if time_varying:
-        # persist the final channel/gain so a second run(cfg, state, ...)
-        # resumes from round t0+num_rounds, not the stale round-0 draw
-        state.h = np.asarray(jax.device_get(h), np.float64)
-        state.h_hat = (state.h if h_hat is None
-                       else np.asarray(jax.device_get(h_hat), np.float64))
-        state.b = np.asarray(jax.device_get(b), np.float64)
-        state.a = float(a)
-    if fad_state is not None:
-        # the correlated fading process continues where it left off
-        state.fad_state = np.asarray(jax.device_get(fad_state), np.float64)
-    state.round += num_rounds
+    with obsprof.span("runtime.epilogue"):
+        state.params = params
+        state.opt_state = opt_state
+        if client_state is not None:
+            state.client_state = client_state
+        if time_varying:
+            # persist the final channel/gain so a second run(cfg, state, ...)
+            # resumes from round t0+num_rounds, not the stale round-0 draw
+            state.h = np.asarray(jax.device_get(h), np.float64)
+            state.h_hat = (state.h if h_hat is None
+                           else np.asarray(jax.device_get(h_hat), np.float64))
+            state.b = np.asarray(jax.device_get(b), np.float64)
+            state.a = float(a)
+        if fad_state is not None:
+            # the correlated fading process continues where it left off
+            state.fad_state = np.asarray(jax.device_get(fad_state), np.float64)
+        state.round += num_rounds
     return state, hist
 
 
@@ -1782,103 +1823,108 @@ def run_batched(cfgs: Sequence[FLConfig], states: Sequence[FLState],
                          "run shares one task")
     model_dim = dims.pop()
 
-    opt = server_optimizer(cfg0)
-    alg0 = clientlib.get(cfg0.client.algo)
-    for s in states:
-        if s.opt_state is None:
-            s.opt_state = opt.init(s.params)._replace(
-                step=jnp.asarray(s.round, jnp.int32))
-        if alg0.stateful and s.client_state is None:
-            s.client_state = clientlib.init_state(cfg0.client, s.params,
-                                                  cfg0.num_devices)
+    with obsprof.span("runtime.prologue"):
+        opt = server_optimizer(cfg0)
+        alg0 = clientlib.get(cfg0.client.algo)
+        for s in states:
+            if s.opt_state is None:
+                s.opt_state = opt.init(s.params)._replace(
+                    step=jnp.asarray(s.round, jnp.int32))
+            if alg0.stateful and s.client_state is None:
+                s.client_state = clientlib.init_state(cfg0.client, s.params,
+                                                      cfg0.num_devices)
 
-    # assemble the per-experiment numerics in NumPy — ONE host->device
-    # transfer per stacked array, not one dispatch per experiment (the
-    # stacking cost is per run_sweep call, so it must stay off the grid's
-    # critical path)
-    params = _stack_trees([s.params for s in states])
-    opt_state = _stack_trees([s.opt_state for s in states])
-    client_state = (_stack_trees([s.client_state for s in states])
-                    if alg0.stateful else None)
-    h = jnp.asarray(np.stack([np.asarray(s.h) for s in states]), jnp.float32)
-    # perfect CSI across the whole sub-batch is structural (h_hat aliases h
-    # in-trace); ANY imperfect lane threads the stacked estimates, and the
-    # perfect lanes among them stay exact (their estimation noise term is a
-    # traced-zero multiple)
-    csi_off = all(c.channel.csi_error == 0.0 for c in cfgs)
-    h_hat = None if csi_off else jnp.asarray(
-        np.stack([np.asarray(s.h_hat if s.h_hat is not None else s.h)
-                  for s in states]), jnp.float32)
-    b = jnp.asarray(np.stack([np.asarray(s.b) for s in states]), jnp.float32)
-    a = jnp.asarray(np.asarray([s.a for s in states]), jnp.float32)
-    eta0 = jnp.asarray(np.asarray([s.eta0 for s in states]), jnp.float32)
-    keys = jnp.stack([jax.random.PRNGKey(c.seed + 1) for c in cfgs])
-    chan_keys = jnp.stack([jax.random.PRNGKey(c.seed + 2) for c in cfgs])
-    model = chl.get(cfg0.channel.model)
-    time_varying = cfg0.channel.time_varying()
-    eff_gain = jnp.zeros((num_exp,), jnp.float32)
-    fad_state = None
-    if model.has_state:
-        if any(s.fad_state is None for s in states):
-            raise ValueError(
-                f"channel model {cfg0.channel.model!r} threads a persistent "
-                "fading state; build the states via setup()")
-        fad_state = jnp.asarray(
-            np.stack([np.asarray(s.fad_state) for s in states]), jnp.float32)
-    if time_varying:
-        if model_dim <= 0:
-            raise ValueError("a time-varying channel re-solves Problem 3 "
-                             "with the real model dimension; "
-                             "FLState.model_dim is unset — build the states "
-                             "via setup()")
-        eff_gain = jnp.asarray(
-            np.asarray([s.a * float(np.sum(np.asarray(
-                s.h_hat if s.h_hat is not None else s.h, np.float64)
-                * np.asarray(s.b, np.float64)))
-                for s in states]), jnp.float32)
+        # assemble the per-experiment numerics in NumPy — ONE host->device
+        # transfer per stacked array, not one dispatch per experiment (the
+        # stacking cost is per run_sweep call, so it must stay off the grid's
+        # critical path)
+        params = _stack_trees([s.params for s in states])
+        opt_state = _stack_trees([s.opt_state for s in states])
+        client_state = (_stack_trees([s.client_state for s in states])
+                        if alg0.stateful else None)
+        h = jnp.asarray(np.stack([np.asarray(s.h) for s in states]),
+                        jnp.float32)
+        # perfect CSI across the whole sub-batch is structural (h_hat aliases h
+        # in-trace); ANY imperfect lane threads the stacked estimates, and the
+        # perfect lanes among them stay exact (their estimation noise term is a
+        # traced-zero multiple)
+        csi_off = all(c.channel.csi_error == 0.0 for c in cfgs)
+        h_hat = None if csi_off else jnp.asarray(
+            np.stack([np.asarray(s.h_hat if s.h_hat is not None else s.h)
+                      for s in states]), jnp.float32)
+        b = jnp.asarray(np.stack([np.asarray(s.b) for s in states]),
+                        jnp.float32)
+        a = jnp.asarray(np.asarray([s.a for s in states]), jnp.float32)
+        eta0 = jnp.asarray(np.asarray([s.eta0 for s in states]), jnp.float32)
+        keys = jnp.stack([jax.random.PRNGKey(c.seed + 1) for c in cfgs])
+        chan_keys = jnp.stack([jax.random.PRNGKey(c.seed + 2) for c in cfgs])
+        model = chl.get(cfg0.channel.model)
+        time_varying = cfg0.channel.time_varying()
+        eff_gain = jnp.zeros((num_exp,), jnp.float32)
+        fad_state = None
+        if model.has_state:
+            if any(s.fad_state is None for s in states):
+                raise ValueError(
+                    f"channel model {cfg0.channel.model!r} threads a "
+                    "persistent fading state; build the states via setup()")
+            fad_state = jnp.asarray(
+                np.stack([np.asarray(s.fad_state) for s in states]),
+                jnp.float32)
+        if time_varying:
+            if model_dim <= 0:
+                raise ValueError("a time-varying channel re-solves "
+                                 "Problem 3 with the real model dimension; "
+                                 "FLState.model_dim is unset — build the "
+                                 "states via setup()")
+            eff_gain = jnp.asarray(
+                np.asarray([s.a * float(np.sum(np.asarray(
+                    s.h_hat if s.h_hat is not None else s.h, np.float64)
+                    * np.asarray(s.b, np.float64)))
+                    for s in states]), jnp.float32)
 
-    def _scales():
-        # in-scan redraw scale: [E, K] geometry-heterogeneous per-device
-        # vectors (drawn at setup, living on the states) or [E] scalars
-        if cfg0.channel.geometry is not None:
-            return jnp.asarray(np.stack([np.asarray(s.scale)
-                                         for s in states]), jnp.float32)
-        return jnp.asarray(
-            np.asarray([c.channel.amplitude_scale() for c in cfgs]),
-            jnp.float32)
+        def _scales():
+            # in-scan redraw scale: [E, K] geometry-heterogeneous per-device
+            # vectors (drawn at setup, living on the states) or [E] scalars
+            if cfg0.channel.geometry is not None:
+                return jnp.asarray(np.stack([np.asarray(s.scale)
+                                             for s in states]), jnp.float32)
+            return jnp.asarray(
+                np.asarray([c.channel.amplitude_scale() for c in cfgs]),
+                jnp.float32)
 
-    over = BatchAxes(
-        noise_var=jnp.asarray(
-            np.asarray([c.channel.noise_var for c in cfgs]), jnp.float32),
-        grad_bound=(None if cfg0.grad_bound is None else jnp.asarray(
-            np.asarray([c.grad_bound for c in cfgs]), jnp.float32)),
-        b_max=(jnp.asarray(np.asarray([c.channel.b_max for c in cfgs]),
-                           jnp.float32) if time_varying else None),
-        rayleigh_scale=(_scales() if time_varying else None),
-        rho=(jnp.asarray(np.asarray([c.channel.rho for c in cfgs]),
-                         jnp.float32) if time_varying else None),
-        csi_error=(jnp.asarray(
-            np.asarray([c.channel.csi_error for c in cfgs]), jnp.float32)
-            if time_varying and not csi_off else None),
-        # exactly the numerics the algorithm declares it reads become lanes
-        # (an unused lane would change the default traces for nothing)
-        client_mu=(jnp.asarray(
-            np.asarray([c.client.mu for c in cfgs]), jnp.float32)
-            if alg0.uses_mu else None),
-        client_alpha=(jnp.asarray(
-            np.asarray([c.client.alpha for c in cfgs]), jnp.float32)
-            if alg0.uses_alpha else None),
-    )
+        over = BatchAxes(
+            noise_var=jnp.asarray(
+                np.asarray([c.channel.noise_var for c in cfgs]), jnp.float32),
+            grad_bound=(None if cfg0.grad_bound is None else jnp.asarray(
+                np.asarray([c.grad_bound for c in cfgs]), jnp.float32)),
+            b_max=(jnp.asarray(np.asarray([c.channel.b_max for c in cfgs]),
+                               jnp.float32) if time_varying else None),
+            rayleigh_scale=(_scales() if time_varying else None),
+            rho=(jnp.asarray(np.asarray([c.channel.rho for c in cfgs]),
+                             jnp.float32) if time_varying else None),
+            csi_error=(jnp.asarray(
+                np.asarray([c.channel.csi_error for c in cfgs]), jnp.float32)
+                if time_varying and not csi_off else None),
+            # exactly the numerics the algorithm declares it reads become lanes
+            # (an unused lane would change the default traces for nothing)
+            client_mu=(jnp.asarray(
+                np.asarray([c.client.mu for c in cfgs]), jnp.float32)
+                if alg0.uses_mu else None),
+            client_alpha=(jnp.asarray(
+                np.asarray([c.client.alpha for c in cfgs]), jnp.float32)
+                if alg0.uses_alpha else None),
+        )
 
-    if shard:
-        from repro.distribution import sharding as shardlib
-        mesh = shardlib.experiment_mesh(num_exp)
-        if mesh is not None:
-            (params, opt_state, client_state, h, h_hat, b, a, eta0, keys,
-             chan_keys, eff_gain, fad_state, over) = \
-                shardlib.shard_experiment_axis(
-                    (params, opt_state, client_state, h, h_hat, b, a, eta0,
-                     keys, chan_keys, eff_gain, fad_state, over), mesh)
+        if shard:
+            from repro.distribution import sharding as shardlib
+            mesh = shardlib.experiment_mesh(num_exp)
+            if mesh is not None:
+                (params, opt_state, client_state, h, h_hat, b, a, eta0, keys,
+                 chan_keys, eff_gain, fad_state, over) = \
+                    shardlib.shard_experiment_axis(
+                        (params, opt_state, client_state, h, h_hat, b, a, eta0,
+                         keys, chan_keys, eff_gain, fad_state, over), mesh)
+        run_chunk = _make_run_chunk_batched(sig, grad_fn, model_dim)
 
     hist: Dict[str, Any] = {"round": [], "eval_round": []}
     diag_chunks: Dict[str, List[np.ndarray]] = {k: [] for k in DIAG_KEYS}
@@ -1887,71 +1933,76 @@ def run_batched(cfgs: Sequence[FLConfig], states: Sequence[FLState],
 
     def record_eval(params, t):
         nonlocal eval_keys
-        per_exp: Dict[str, List[float]] = {}
-        for e in range(num_exp):
-            metrics = eval_fn(_slice_tree(params, e))
-            eval_keys = _locked_eval_keys(metrics, eval_keys, t,
-                                          where=f" (experiment {e})")
+        with obsprof.span("runtime.eval"):
+            per_exp: Dict[str, List[float]] = {}
+            for e in range(num_exp):
+                metrics = eval_fn(_slice_tree(params, e))
+                eval_keys = _locked_eval_keys(metrics, eval_keys, t,
+                                              where=f" (experiment {e})")
+                for mk in eval_keys:
+                    per_exp.setdefault(mk, []).append(metrics[mk])
             for mk in eval_keys:
-                per_exp.setdefault(mk, []).append(metrics[mk])
-        for mk in eval_keys:
-            eval_chunks.setdefault(mk, []).append(per_exp[mk])
-        hist["eval_round"].append(t)
-        if recorder is not None:
-            recorder.on_eval(t, {mk: [float(v) for v in per_exp[mk]]
-                                 for mk in eval_keys})
+                eval_chunks.setdefault(mk, []).append(per_exp[mk])
+            hist["eval_round"].append(t)
+            if recorder is not None:
+                recorder.on_eval(t, {mk: [float(v) for v in per_exp[mk]]
+                                     for mk in eval_keys})
 
-    run_chunk = _make_run_chunk_batched(sig, grad_fn, model_dim)
     for chunk_i, ts in enumerate(_plan_chunks(
             t0, num_rounds, eval_every if eval_fn is not None else None,
             chunk_size)):
-        if recorder is not None:
-            tr0 = dict(TRACE_COUNTS)
-            wt0 = time.perf_counter()
         with obsprof.annotate_chunk(chunk_i):
-            batches = (chunk_batch_provider(ts) if chunk_batch_provider
-                       else _stack_batches(batch_provider, ts))
-            (params, opt_state, client_state, h, h_hat, b, a, fad_state,
-             chunk_hist) = run_chunk(
-                 params, opt_state, client_state, h, h_hat, b, a, eta0, keys,
-                 chan_keys, eff_gain, fad_state, over,
-                 jnp.asarray(ts, jnp.int32), batches)
-            chunk_hist = jax.device_get(chunk_hist)   # ONE sync per chunk
-        hist["round"].extend(ts)
+            if recorder is not None:
+                tr0 = dict(TRACE_COUNTS)
+                wt0 = time.perf_counter()
+            with obsprof.span("runtime.batches"):
+                batches = (chunk_batch_provider(ts) if chunk_batch_provider
+                           else _stack_batches(batch_provider, ts))
+            with obsprof.span("runtime.dispatch"):
+                (params, opt_state, client_state, h, h_hat, b, a, fad_state,
+                 chunk_hist) = run_chunk(
+                     params, opt_state, client_state, h, h_hat, b, a, eta0,
+                     keys, chan_keys, eff_gain, fad_state, over,
+                     jnp.asarray(ts, jnp.int32), batches)
+            with obsprof.span("runtime.history_get"):
+                chunk_hist = jax.device_get(chunk_hist)  # ONE sync per chunk
+            hist["round"].extend(ts)
+            for k in DIAG_KEYS:
+                diag_chunks[k].append(np.asarray(chunk_hist[k], np.float64))
+            if recorder is not None:
+                # [E, T] per-chunk diagnostics: on_chunk fans them out into
+                # one round event per t with [E] value lists
+                recorder.on_chunk(
+                    chunk_i, list(ts),
+                    {k: np.asarray(chunk_hist[k]) for k in DIAG_KEYS},
+                    wall_time_s=time.perf_counter() - wt0,
+                    dispatches=1,
+                    retraces=trace_deltas(tr0),
+                    rss_mb=obsprof.rss_mb())
+            t_end = ts[-1]
+            if eval_fn is not None and (t_end % eval_every == 0
+                                        or t_end == 1):
+                record_eval(params, t_end)
+
+    with obsprof.span("runtime.epilogue"):
         for k in DIAG_KEYS:
-            diag_chunks[k].append(np.asarray(chunk_hist[k], np.float64))
-        if recorder is not None:
-            # [E, T] per-chunk diagnostics: on_chunk fans them out into one
-            # round event per t with [E] value lists
-            recorder.on_chunk(
-                chunk_i, list(ts),
-                {k: np.asarray(chunk_hist[k]) for k in DIAG_KEYS},
-                wall_time_s=time.perf_counter() - wt0,
-                dispatches=1,
-                retraces=trace_deltas(tr0),
-                rss_mb=obsprof.rss_mb())
-        t_end = ts[-1]
-        if eval_fn is not None and (t_end % eval_every == 0 or t_end == 1):
-            record_eval(params, t_end)
+            hist[k] = np.concatenate(diag_chunks[k], axis=1)       # [E, T]
+        for mk, cols in eval_chunks.items():
+            hist[mk] = np.asarray(cols, np.float64).T              # [E, evals]
 
-    for k in DIAG_KEYS:
-        hist[k] = np.concatenate(diag_chunks[k], axis=1)       # [E, T]
-    for mk, cols in eval_chunks.items():
-        hist[mk] = np.asarray(cols, np.float64).T              # [E, evals]
-
-    for e, s in enumerate(states):
-        s.params = _slice_tree(params, e)
-        s.opt_state = _slice_tree(opt_state, e)
-        if alg0.stateful:
-            s.client_state = _slice_tree(client_state, e)
-        if time_varying:
-            s.h = np.asarray(jax.device_get(h[e]), np.float64)
-            s.h_hat = (s.h if h_hat is None
-                       else np.asarray(jax.device_get(h_hat[e]), np.float64))
-            s.b = np.asarray(jax.device_get(b[e]), np.float64)
-            s.a = float(a[e])
-        if fad_state is not None:
-            s.fad_state = np.asarray(jax.device_get(fad_state[e]),
-                                     np.float64)
-        s.round += num_rounds
+        for e, s in enumerate(states):
+            s.params = _slice_tree(params, e)
+            s.opt_state = _slice_tree(opt_state, e)
+            if alg0.stateful:
+                s.client_state = _slice_tree(client_state, e)
+            if time_varying:
+                s.h = np.asarray(jax.device_get(h[e]), np.float64)
+                s.h_hat = (s.h if h_hat is None else
+                           np.asarray(jax.device_get(h_hat[e]), np.float64))
+                s.b = np.asarray(jax.device_get(b[e]), np.float64)
+                s.a = float(a[e])
+            if fad_state is not None:
+                s.fad_state = np.asarray(jax.device_get(fad_state[e]),
+                                         np.float64)
+            s.round += num_rounds
     return list(states), hist

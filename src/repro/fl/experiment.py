@@ -100,28 +100,31 @@ class Experiment:
         ``recorder`` (or the constructor's default) streams the run live —
         one manifest event, then chunk/round/eval events from the engine;
         with ``REPRO_OBS_PROFILE`` set, the whole call is wrapped in a
-        ``jax.profiler`` trace.  Both are trajectory-invisible."""
-        self._ensure_setup()
-        ev = self.spec.eval
-        enabled = ev.enabled if evaluate is None else evaluate
-        rec = recorder if recorder is not None else self.recorder
-        if rec is not None:
-            rec.on_manifest(self.manifest())
+        ``jax.profiler`` trace.  Under any active trace the call is the
+        ``fl.run`` span.  All are trajectory-invisible."""
         handle = obs.profiling.start_profile()
         try:
-            self.state, hist = runtime.run(
-                self.cfg, self.state, self.task.grad_fn,
-                self.task.batch_provider, num_rounds,
-                eval_fn=self.task.eval_fn if enabled else None,
-                eval_every=eval_every if eval_every is not None else ev.every,
-                driver=driver or self.spec.driver,
-                chunk_size=chunk_size or self.spec.chunk_size,
-                chunk_batch_provider=self.task.chunk_batch_provider,
-                recorder=rec)
+            with obs.profiling.span("fl.run"):
+                self._ensure_setup()
+                ev = self.spec.eval
+                enabled = ev.enabled if evaluate is None else evaluate
+                rec = recorder if recorder is not None else self.recorder
+                if rec is not None:
+                    rec.on_manifest(self.manifest())
+                self.state, hist = runtime.run(
+                    self.cfg, self.state, self.task.grad_fn,
+                    self.task.batch_provider, num_rounds,
+                    eval_fn=self.task.eval_fn if enabled else None,
+                    eval_every=(eval_every if eval_every is not None
+                                else ev.every),
+                    driver=driver or self.spec.driver,
+                    chunk_size=chunk_size or self.spec.chunk_size,
+                    chunk_batch_provider=self.task.chunk_batch_provider,
+                    recorder=rec)
+                for k, v in hist.items():
+                    self.history.setdefault(k, []).extend(v)
         finally:
             obs.profiling.stop_profile(handle)
-        for k, v in hist.items():
-            self.history.setdefault(k, []).extend(v)
         return hist
 
     # ---------------------------------------------------------- observability

@@ -7,9 +7,10 @@ Three layers, all host-side:
   ``recorder`` and emit the per-chunk ``DIAG_KEYS`` series, eval metrics,
   per-chunk wall clock, dispatch counts, and re-trace attribution at chunk
   boundaries (after the on-device chunk returns — never inside the trace).
-* **Profiling hooks** (:mod:`repro.obs.profiling`) — ``REPRO_OBS_PROFILE``
-  env-gated ``jax.profiler`` traces around runs and chunks, plus the
-  /proc RSS readers the K-scale benchmark pioneered.
+* **Profiling hooks** (:mod:`repro.obs.profiling`) — the engine's host
+  spans (recorded under any active ``jax.profiler`` trace),
+  ``REPRO_OBS_PROFILE`` env-gated traces around runs, plus the /proc RSS
+  readers the K-scale benchmark pioneered.
 * **Run manifests** (:mod:`repro.obs.manifest`) — spec JSON, structural
   signature, params sha-256, config hash, jax/platform versions: the
   identity block ``results/`` files and recorder streams carry.

@@ -1,12 +1,21 @@
-"""Profiling hooks: env-gated ``jax.profiler`` traces and /proc RSS
-sampling.
+"""Profiling hooks: the program's host spans, env-gated ``jax.profiler``
+traces, and /proc RSS sampling.
 
-Everything here is host-side and inert by default: with ``REPRO_OBS_PROFILE``
-unset, ``start_profile`` returns ``None`` and ``annotate_chunk`` hands back a
-shared null context, so the engine's chunk loop pays nothing.  Setting the
-variable to a directory turns every ``Experiment.run`` into a profiler trace
-(``start_trace``/``stop_trace`` around the run, one ``StepTraceAnnotation``
-per engine chunk) viewable in TensorBoard/Perfetto.
+:func:`span` is the program's one span mechanism: a
+``jax.profiler.TraceAnnotation`` (a ``StepTraceAnnotation`` when given a
+step number), recorded on the profiler's clock whenever any profiler trace
+is active, whoever started it (this module, the benchmark harness,
+TensorBoard's remote capture), and about a microsecond when none is.  The
+engine names its host steps with it (``fl.run``, ``runtime.prologue``,
+``runtime.chunk``, ``runtime.batches``, ``runtime.dispatch``,
+``runtime.history_get``, ``runtime.eval``, ``runtime.epilogue``), so a
+trace puts device-idle time down to the step the host was in.  Spans sit
+at chunk granularity, outside traced code.
+
+``REPRO_OBS_PROFILE`` starts a trace: set to a directory, it turns every
+``Experiment.run`` into a profiler trace (``start_trace``/``stop_trace``
+around the run) viewable in TensorBoard/Perfetto.  Unset,
+``start_profile`` returns ``None``.
 
 The RSS readers are the ``benchmarks/kscale_case.py`` /proc pattern promoted
 to a library: ``VmHWM`` (peak) is a property of the current mm — exec-fresh,
@@ -15,14 +24,12 @@ per-chunk sample the recorder's ``chunk`` events carry.
 """
 from __future__ import annotations
 
-import contextlib
 import os
 import resource
 from typing import Optional
 
 PROFILE_ENV = "REPRO_OBS_PROFILE"
 
-_NULL_CTX = contextlib.nullcontext()
 # one trace at a time: nested Experiment.run calls (sweep fallbacks) must
 # not try to re-enter jax.profiler.start_trace
 _ACTIVE = False
@@ -31,10 +38,6 @@ _ACTIVE = False
 def profile_dir() -> Optional[str]:
     """The profiler output directory, or None when profiling is off."""
     return os.environ.get(PROFILE_ENV) or None
-
-
-def enabled() -> bool:
-    return profile_dir() is not None
 
 
 def _proc_status_mb(field: str) -> Optional[float]:
@@ -89,11 +92,18 @@ def stop_profile(handle: Optional[str]) -> None:
     _ACTIVE = False
 
 
-def annotate_chunk(index: int):
-    """A ``StepTraceAnnotation`` naming one engine chunk inside an active
-    profile; the shared null context when profiling is off."""
-    if not enabled():
-        return _NULL_CTX
+def span(name: str, step: Optional[int] = None):
+    """A host span named ``name`` (with ``step`` as its step number), recorded
+    whenever a profiler trace is active.  Use as a context manager around
+    host code only, never inside a traced function."""
     import jax
 
-    return jax.profiler.StepTraceAnnotation("obs_chunk", step_num=int(index))
+    if step is None:
+        return jax.profiler.TraceAnnotation(name)
+    return jax.profiler.StepTraceAnnotation(name, step_num=int(step))
+
+
+def annotate_chunk(index: int):
+    """The ``runtime.chunk`` span of one engine chunk (step number
+    ``index``)."""
+    return span("runtime.chunk", step=index)

@@ -12,12 +12,15 @@ mesh train-step instrumentation wrapper, and the ``benchmarks.compare
 --manifest`` structural-signature cross-check (CLI, like test_lint's
 self-test checks).
 """
+import glob
 import json
 import pathlib
+import re
 import subprocess
 import sys
 import urllib.request
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -302,10 +305,130 @@ class TestProfiling:
 
     def test_disabled_without_env(self, monkeypatch):
         monkeypatch.delenv(obs.profiling.PROFILE_ENV, raising=False)
-        assert not obs.profiling.enabled()
+        assert obs.profiling.profile_dir() is None
         assert obs.profiling.start_profile() is None
         with obs.profiling.annotate_chunk(0):
             pass
+
+
+def traced_host_spans(tmp_path, body):
+    """Run ``body`` under a profiler trace started here (as an outside tool
+    would) and return the host plane's events as (name, start, end) ns."""
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True)
+    prof = jax.profiler.ProfileData.from_file(path)
+    return [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+            for plane in prof.planes if plane.name.startswith("/host:CPU")
+            for line in plane.lines for ev in line.events]
+
+
+def assert_nested(spans, outer, inner, every=True):
+    """Every ``inner`` span lies inside some ``outer`` span, and (``every``)
+    every ``outer`` span holds at least one ``inner`` span."""
+    outs = [(s, e) for n, s, e in spans if n == outer]
+    ins = [(s, e) for n, s, e in spans if n == inner]
+    assert outs and ins, (outer, inner)
+    assert all(any(os <= s and e <= oe for os, oe in outs) for s, e in ins)
+    if every:
+        assert all(any(os <= s and e <= oe for s, e in ins)
+                   for os, oe in outs)
+
+
+class TestProgramSpans:
+    """The engine's host spans are recorded under any active profiler trace,
+    with ``REPRO_OBS_PROFILE`` unset, nested as the host loop runs them."""
+
+    @pytest.mark.parametrize("driver", ("scan", "python"))
+    def test_experiment_run_spans(self, tmp_path, monkeypatch, driver):
+        monkeypatch.delenv(obs.profiling.PROFILE_ENV, raising=False)
+        e = Experiment(ridge_spec())
+        e.setup()
+        spans = traced_host_spans(tmp_path,
+                                  lambda: e.run(ROUNDS, driver=driver))
+        for inner in ("runtime.prologue", "runtime.chunk",
+                      "runtime.epilogue"):
+            assert_nested(spans, "fl.run", inner)
+        for inner in ("runtime.batches", "runtime.dispatch",
+                      "runtime.history_get"):
+            assert_nested(spans, "runtime.chunk", inner)
+        # eval at t = 1 and every 5th round, inside the chunk that ends there
+        assert sum(n == "runtime.eval" for n, _, _ in spans) == 2
+        assert_nested(spans, "runtime.chunk", "runtime.eval", every=False)
+        chunks = sum(n == "runtime.chunk" for n, _, _ in spans)
+        assert chunks == (ROUNDS if driver == "python" else len(
+            rt._plan_chunks(0, ROUNDS, 5, 3)))
+
+    def test_run_batched_spans(self, tmp_path, monkeypatch):
+        monkeypatch.delenv(obs.profiling.PROFILE_ENV, raising=False)
+        sweep = SweepSpec(ridge_spec(), {"eta": (0.01, 0.02)})
+        spans = traced_host_spans(tmp_path, lambda: run_sweep(sweep, ROUNDS))
+        names = {n for n, _, _ in spans}
+        assert {"runtime.prologue", "runtime.epilogue"} <= names
+        for inner in ("runtime.batches", "runtime.dispatch",
+                      "runtime.history_get"):
+            assert_nested(spans, "runtime.chunk", inner)
+        assert_nested(spans, "runtime.chunk", "runtime.eval", every=False)
+
+    @pytest.mark.parametrize("driver", ("scan", "python"))
+    def test_trace_is_trajectory_invisible(self, tmp_path, driver):
+        spec = ridge_spec(channel=ChannelConfig(
+            num_devices=K, channel_mean=1e-3, block_fading=True))
+        e_off = Experiment(spec)
+        h_off = e_off.run(ROUNDS, driver=driver)
+        e_on = Experiment(spec)
+        e_on.setup()
+        h_on = {}
+        traced_host_spans(tmp_path, lambda: h_on.update(
+            e_on.run(ROUNDS, driver=driver)))
+        assert (obs.params_sha256(e_on.state.params)
+                == obs.params_sha256(e_off.state.params))
+        assert h_on == h_off
+
+
+STAGES = ("fading_refresh", "local_step", "aggregate", "server_update")
+
+
+def round_program_hlo(monkeypatch, spec) -> str:
+    """The optimized HLO text of the round program that ``Experiment.run``
+    compiles for ``spec`` (lowered from the same arguments)."""
+    texts = []
+    build = rt._make_run_chunk
+
+    def spy(*a, **kw):
+        fn = build(*a, **kw)
+
+        def call(*args):
+            texts.append(fn.lower(*args).compile().as_text())
+            return fn(*args)
+        return call
+
+    monkeypatch.setattr(rt, "_make_run_chunk", spy)
+    Experiment(spec).run(3, evaluate=False)
+    return texts[0]
+
+
+class TestStageScopes:
+    """Each stage of the round program carries its ``jax.named_scope`` in
+    the compiled ops' ``op_name`` metadata, on the dense and streamed
+    paths."""
+
+    @pytest.mark.parametrize("k_block", (None, 2))
+    def test_every_stage_names_ops(self, monkeypatch, k_block):
+        spec = ridge_spec(k_block=k_block, channel=ChannelConfig(
+            num_devices=K, channel_mean=1e-3, block_fading=True))
+        hlo = round_program_hlo(monkeypatch, spec)
+        op_names = re.findall(r'op_name="([^"]*)"', hlo)
+        for stage in STAGES:
+            assert any(f"/{stage}/" in n for n in op_names), stage
+        if k_block is not None:
+            # the K-block scan is the aggregation's: its loop carries it
+            loops = re.findall(r'= [^\n]* while\([^\n]*op_name="([^"]*)"',
+                               hlo)
+            assert any("/aggregate/" in n for n in loops)
 
 
 class TestLiveMetrics:
